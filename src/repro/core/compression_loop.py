@@ -101,13 +101,18 @@ def run_ladder(
     student = distillation.init_student_from_teacher(teacher_params, s_cfg, rng)
     opt = get_optimizer("adamw", lc.lr)
 
-    def d_loss(p, b):
-        return distillation.distill_loss(p, teacher_params, b, s_cfg, cfg, rules)
+    # the teacher is an argument, not a closure: jit would embed closed-over
+    # arrays in the program as constants (all of its tables, at full size)
+    @jax.jit
+    def step(p, state, b, teacher):
+        def d_loss(p, b):
+            return distillation.distill_loss(p, teacher, b, s_cfg, cfg, rules)
 
-    step = jax.jit(make_train_step(d_loss, opt))
+        return make_train_step(d_loss, opt)(p, state, b)
+
     state = opt.init(student)
     for i, b in zip(range(lc.distill_steps), batch_fn()):
-        student, state, m = step(student, state, b)
+        student, state, m = step(student, state, b, teacher_params)
     out["distilled"] = {"params": student, "cfg": s_cfg}
     return out
 

@@ -31,15 +31,6 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.sharding import pspec
 
 
-def _axis_size(axis) -> int:
-    try:
-        if hasattr(jax.lax, "axis_size"):
-            return jax.lax.axis_size(axis)
-        return jax.lax.psum(1, axis)  # jax 0.4.x: constant-folds to the size
-    except NameError:
-        return 1
-
-
 def _sort_dispatch(keys: jax.Array, n_bins: int, capacity: int):
     """entries -> (slot, kept): slot = bin*capacity + rank within bin
     (rank >= capacity dropped). keys: [N] int32 in [0, n_bins)."""
@@ -99,7 +90,7 @@ def moe_ffn_ep(x, layer_params, cfg, rules) -> Tuple[jax.Array, jax.Array]:
     E, K, cf = cfg.n_experts, cfg.top_k, cfg.capacity_factor
 
     def body(x_loc, p_loc):
-        n_groups = _axis_size(ep_axis)
+        n_groups = jax.lax.axis_size(ep_axis)
         E_loc = E // n_groups
         B_loc, S_loc, D = x_loc.shape
         T_loc = B_loc * S_loc
@@ -197,10 +188,8 @@ def moe_ffn_ep(x, layer_params, cfg, rules) -> Tuple[jax.Array, jax.Array]:
     # check_vma=False: under some layouts (e.g. TP train, seq unsharded) the
     # router aux is invariant along the expert axis and the VMA checker
     # rejects the (correct) pmean over it.
-    from repro.distributed.sharding import shard_map_compat
-
-    fn = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     moe_in = {k: layer_params[k] for k in
               ("router", "w_gate", "w_up", "w_down") if k in layer_params}
     if has_shared:

@@ -131,21 +131,6 @@ def pspec(axes: Sequence[Optional[str]], rules: Mapping[str, MeshAxes]) -> P:
     return P(*out)
 
 
-def shard_map_compat(body, *, mesh: Mesh, in_specs, out_specs, check=False):
-    """jax.shard_map across jax versions: 0.4.x ships it as
-    jax.experimental.shard_map with the replication checker named check_rep;
-    newer jax hangs it off the top-level namespace with check_vma."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
-
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 def named_sharding(mesh: Mesh, axes: Sequence[Optional[str]], rules) -> NamedSharding:
     return NamedSharding(mesh, pspec(axes, rules))
 

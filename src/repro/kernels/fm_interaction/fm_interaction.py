@@ -18,7 +18,7 @@ def _kernel(e_ref, out_ref):
     e = e_ref[...]  # [bb, F, k]
     s = jnp.sum(e, axis=1)  # [bb, k]
     sq = jnp.sum(jnp.square(e), axis=1)  # [bb, k]
-    out_ref[...] = 0.5 * jnp.sum(jnp.square(s) - sq, axis=-1)
+    out_ref[...] = 0.5 * jnp.sum(jnp.square(s) - sq, axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
@@ -29,7 +29,9 @@ def fm_interaction_kernel(e, *, bb: int = 256, interpret: bool = False):
         _kernel,
         grid=(B // bb,),
         in_specs=[pl.BlockSpec((bb, F, k), lambda b: (b, 0, 0))],
-        out_specs=pl.BlockSpec((bb,), lambda b: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
+        # a [bb, 1] column block: a 1-D (bb,) block does not match the
+        # chip's tiled layout of a rank-1 operand
+        out_specs=pl.BlockSpec((bb, 1), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
         interpret=interpret,
-    )(e)
+    )(e)[:, 0]

@@ -33,8 +33,9 @@ def _kernel(a_ref, b_ref, as_ref, bs_ref, out_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _epilogue():
-        scale = as_ref[...][:, None] * bs_ref[...][None, :]
-        out_ref[...] = acc_ref[...].astype(jnp.float32) * scale
+        # [bm, 1] x [1, bn] broadcast: the scales stay 2-D so their blocks
+        # tile like any other operand on the chip
+        out_ref[...] = acc_ref[...].astype(jnp.float32) * as_ref[...] * bs_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -42,9 +43,12 @@ def int8_matmul(
     a_q, b_q, a_scale, b_scale, *, bm: int = 128, bn: int = 128, bk: int = 128,
     interpret: bool = False,
 ):
-    """a_q int8 [M,K] (M,K multiples of bm,bk); b_q int8 [K,N]."""
+    """a_q int8 [M,K]; b_q int8 [K,N]; a_scale f32 [M]; b_scale f32 [N].
+    Each of M, N, K is a multiple of its block, or equal to it."""
     M, K = a_q.shape
     _, N = b_q.shape
+    if M % bm or N % bn or K % bk:
+        raise ValueError(f"[{M},{K}] x [{K},{N}] does not tile into ({bm}, {bn}, {bk}) blocks")
     n_k = K // bk
     grid = (M // bm, N // bn, n_k)
     return pl.pallas_call(
@@ -53,11 +57,11 @@ def int8_matmul(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
-            pl.BlockSpec((bm,), lambda m, n, k: (m,)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
+            pl.BlockSpec((bm, 1), lambda m, n, k: (m, 0)),
+            pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(a_q, b_q, a_scale, b_scale)
+    )(a_q, b_q, a_scale.reshape(M, 1), b_scale.reshape(1, N))

@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import (device count locks at first init). This
 # module is the ONLY place the 512-device platform is forced — tests and
-# benchmarks see the real 1-device CPU.
+# benchmarks see the real 1-device CPU. Pinning the CPU keeps the --sweep
+# parent and its per-cell children off an attached TPU, which only one
+# process at a time may hold.
 
 import argparse  # noqa: E402
 import json  # noqa: E402

@@ -5,26 +5,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-# jax >= 0.5 exposes jax.sharding.AxisType and make_mesh grows an axis_types
-# kwarg; on 0.4.x the attribute raises (deprecation shim turns the lookup
-# into an AttributeError at import time). Resolve it once here so every
-# caller builds meshes through a version-tolerant path.
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except (ImportError, AttributeError):  # jax 0.4.x
-    AxisType = None
-
-
-def compat_make_mesh(shape, axis_names, *, devices=None):
-    """jax.make_mesh that passes axis_types only where the installed jax
-    supports it (explicit-sharding AxisType landed after 0.4.x)."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(shape, axis_names, **kwargs)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,9 +20,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under launch/dryrun.py which sets "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512"
         )
-    return compat_make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes), devices=devices[:n])
 
 
 def make_test_mesh():
     """1-device mesh for smoke tests and CPU benchmarks."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)

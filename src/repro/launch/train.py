@@ -1,8 +1,9 @@
 """Training launcher: `python -m repro.launch.train --arch taobao_ssa ...`
 
-Runs a REAL training loop on this host (CPU, reduced config) or AOT-lowers
-at production scale (--dry). Wires: config -> model -> optimizer ->
-fault-tolerant loop (checkpoint/resume) -> metrics log.
+Runs a REAL training loop on the default backend, at the arch's published
+widths (`--size published`) or cut to CPU scale (`--size reduced`, the
+default). Wires: config -> model -> optimizer -> fault-tolerant loop
+(checkpoint/resume) -> metrics log.
 """
 from __future__ import annotations
 
@@ -18,9 +19,9 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.data import synthetic
 from repro.distributed.sharding import FAMILY_RULES, adapt_rules
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.common import init_params
-from repro.training import checkpoint
 from repro.training.fault_tolerance import FTConfig, ResilientTrainer
 from repro.training.optimizer import get_optimizer
 from repro.training.train_loop import make_train_step
@@ -41,6 +42,18 @@ def reduced_config(cfg):
         )
         return dataclasses.replace(cfg, fields=fields)
     return cfg  # nequip is already small
+
+
+SIZES = ("published", "reduced")
+CKPT_ROOT = Path(__file__).resolve().parents[3] / ".ckpt"
+
+
+def sized_config(arch: str, size: str):
+    """The arch's config at its published widths, or cut by reduced_config."""
+    if size not in SIZES:
+        raise ValueError(f"size must be one of {SIZES}, not {size!r}")
+    cfg = get_config(arch)
+    return cfg if size == "published" else reduced_config(cfg)
 
 
 def make_loss(cfg, rules):
@@ -99,15 +112,19 @@ def param_defs_for(cfg):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="taobao_ssa")
+    ap.add_argument("--size", default="reduced", choices=SIZES)
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--ckpt-dir", default="")
+    ap.add_argument("--ckpt-dir", default="",
+                    help="default <repo>/.ckpt/<arch>_<size>; a run resumes from "
+                         "the latest checkpoint there")
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args()
 
-    cfg = reduced_config(get_config(args.arch))
+    use_compile_cache()
+    cfg = sized_config(args.arch, args.size)
     mesh = make_test_mesh()
     rules = adapt_rules(FAMILY_RULES[cfg.family], mesh)
 
@@ -116,7 +133,7 @@ def main():
     opt_state = opt.init(params)
     step_fn = jax.jit(make_train_step(make_loss(cfg, rules), opt))
 
-    ckpt_dir = args.ckpt_dir or f"/tmp/repro_ckpt_{args.arch}"
+    ckpt_dir = args.ckpt_dir or str(CKPT_ROOT / f"{args.arch}_{args.size}")
     trainer = ResilientTrainer(
         step_fn,
         FTConfig(ckpt_dir=ckpt_dir, ckpt_every=args.ckpt_every),
@@ -125,10 +142,12 @@ def main():
     t0 = time.time()
     params, opt_state, restarts, last = trainer.run(params, opt_state, args.steps)
     dt = time.time() - t0
+    taken = last - trainer.resumed_from
     print(
         json.dumps(
-            {"arch": args.arch, "steps": last, "restarts": restarts,
-             "wall_s": round(dt, 2), "steps_per_s": round(last / dt, 2)}
+            {"arch": args.arch, "size": args.size, "resumed_from": trainer.resumed_from,
+             "steps": last, "steps_taken": taken, "restarts": restarts,
+             "wall_s": round(dt, 2), "steps_per_s": round(taken / dt, 2)}
         )
     )
 

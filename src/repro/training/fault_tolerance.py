@@ -88,7 +88,8 @@ class ResilientTrainer:
 
     def run(self, params, opt_state, n_steps: int, *, crash_at: Optional[int] = None):
         """Train with periodic checkpoints; `crash_at` injects a failure
-        (tests). Returns (params, opt_state, restarts, last_step)."""
+        (tests). Returns (params, opt_state, restarts, last_step); the step
+        it resumed from (0 for a fresh start) stays in `self.resumed_from`."""
         start = checkpoint.latest_step(self.cfg.ckpt_dir)
         restarts = 0
         step0 = 0
@@ -98,13 +99,15 @@ class ResilientTrainer:
             )
             step0 = start
             restarts += 1
+        self.resumed_from = step0
 
         batches = self.make_batches(step0)
-        step = step0
+        last = step0  # a resumed run past n_steps takes no step
         for step, batch in zip(range(step0, n_steps), batches):
             if crash_at is not None and step == crash_at:
                 raise RuntimeError(f"injected failure at step {step}")
             params, opt_state, metrics = self.train_step(params, opt_state, batch)
-            if (step + 1) % self.cfg.ckpt_every == 0:
-                checkpoint.save(self.cfg.ckpt_dir, step + 1, (params, opt_state))
-        return params, opt_state, restarts, step + 1
+            last = step + 1
+            if last % self.cfg.ckpt_every == 0:
+                checkpoint.save(self.cfg.ckpt_dir, last, (params, opt_state))
+        return params, opt_state, restarts, last

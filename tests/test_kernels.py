@@ -33,6 +33,30 @@ def test_int8_matmul_shapes(M, K, N, bm, bn, bk):
     assert rel < 0.05
 
 
+@pytest.mark.parametrize("M,K,N", [(200, 128, 128), (128, 192, 128), (128, 128, 64)])
+def test_int8_matmul_rejects_partial_blocks(M, K, N):
+    a_q = jnp.ones((M, K), jnp.int8)
+    w_q = jnp.ones((K, N), jnp.int8)
+    with pytest.raises(ValueError, match="does not tile"):
+        int8_matmul(a_q, w_q, jnp.ones((M,)), jnp.ones((N,)), interpret=True)
+
+
+@pytest.mark.parametrize("M,K,N", [(100, 64, 256), (300, 208, 200), (256, 128, 128)])
+def test_quantized_linear_pads_to_blocks(M, K, N):
+    """Shapes that do not tile run the kernel on zero-padded operands,
+    not the oracle, and still match the oracle."""
+    from repro.core.quantization import quantize_weight
+    from repro.kernels.int8_matmul.ops import quantized_linear
+
+    x = jax.random.normal(jax.random.key(M), (M, K))
+    w_rep = quantize_weight(jax.random.normal(jax.random.key(K), (K, N)))
+    out = quantized_linear(x, w_rep)
+    x_q, x_s = quantize_activations(x)
+    ref = int8_matmul_ref(x_q, w_rep["q"], x_s, w_rep["s"])
+    assert out.shape == (M, N)
+    np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-4)
+
+
 @pytest.mark.parametrize("density", [0.0, 0.3, 0.7, 1.0])
 def test_block_pruned_matmul(density):
     M = K = N = 256
@@ -130,6 +154,16 @@ def test_fm_interaction(B, F, k):
     out = fm_interaction_kernel(e, bb=min(B, 256), interpret=True)
     ref = fm_interaction_ref(e)
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("B", [100, 300])
+def test_fm_interaction_op_pads_batch(B):
+    from repro.kernels.fm_interaction.ops import fm_interaction_op
+
+    e = jax.random.normal(jax.random.key(B), (B, 39, 10))
+    out = fm_interaction_op(e)
+    assert out.shape == (B,)
+    np.testing.assert_allclose(out, fm_interaction_ref(e), rtol=1e-4, atol=1e-3)
 
 
 @pytest.mark.parametrize("B,T,g", [(128, 20, 16), (256, 50, 32)])

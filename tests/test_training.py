@@ -122,6 +122,24 @@ def test_resilient_trainer_resumes_after_crash(tmp_path):
     assert restarts == 1 and last == 50
 
 
+def test_resilient_trainer_resumed_at_the_end_takes_no_step(tmp_path):
+    params, loss_fn = _quadratic_problem()
+    opt = get_optimizer("adamw", 0.05)
+    calls = []
+
+    def step(p, s, b):
+        calls.append(1)
+        return p, s, {}
+
+    trainer = ResilientTrainer(step, FTConfig(ckpt_dir=str(tmp_path), ckpt_every=10),
+                               make_batches=lambda start: iter(lambda: {}, None))
+    state = opt.init(params)
+    assert trainer.run(params, state, 20)[2:] == (0, 20)
+    assert len(calls) == 20 and trainer.resumed_from == 0
+    assert trainer.run(params, state, 20)[2:] == (1, 20)  # resumed, nothing left
+    assert len(calls) == 20 and trainer.resumed_from == 20
+
+
 def test_heartbeat_and_straggler_detection():
     cfg = FTConfig(heartbeat_s=1.0, dead_after=3, straggler_factor=2.0,
                    straggler_patience=2)
