@@ -1,0 +1,116 @@
+"""taobao_ssa as the benchmark drives it: the program's jitted serve step
+(`repro.launch.serve.make_serve_step`, i.e. `api.serve` ->
+`taobao_ssa.serve`) on the program's pointwise batch, one row per
+candidate, and the plain reference beside it.
+
+The weights are the benchmark's, made from the seed on the device
+(`reference.make_weights`); a configuration with `"weights": "int8"` hands
+them to the program through its own `quantization.quantize_tree`, and the
+reference quantizes its copy with its own quantizer.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chipbench.reference import taobao_ssa as ref
+
+HIST_BLOCK = 512     # histories per reference call
+ROW_BLOCK = 65536    # candidate rows per reference call
+BATCH_KEYS = ("user", "item", "category", "hist_item", "hist_category", "hist_len")
+
+
+def program_config(cfg: dict):
+    """The program's RecSysConfig holding the sizes of `cfg`."""
+    from repro.configs.base import FieldSpec
+    from repro.configs.taobao_ssa import config
+
+    if cfg["d_ff"] != 4 * cfg["d_model"] or cfg["embed_dim"] != cfg["d_model"]:
+        raise ValueError("the program's encoder has d_ff = 4 * d_model = 4 * embed_dim")
+    L = cfg["seq_len"]
+    fields = (FieldSpec("user", cfg["users"], dim=cfg["user_dim"]),
+              FieldSpec("item", cfg["items"]),
+              FieldSpec("category", cfg["categories"]),
+              FieldSpec("hist_item", cfg["items"], multi_hot=L, shares="item"),
+              FieldSpec("hist_category", cfg["categories"], multi_hot=L,
+                        shares="category"))
+    return dataclasses.replace(
+        config(), fields=fields, embed_dim=cfg["embed_dim"], seq_len=L,
+        n_attn_layers=cfg["n_blocks"], n_heads=cfg["n_heads"], d_attn=cfg["d_model"],
+        mlp_dims=tuple(cfg["tower"]))
+
+
+class Model:
+    def __init__(self, cfg: dict):
+        from repro.launch.serve import make_serve_step, recsys_rules
+        from repro.models.common import is_def
+        from repro.models.recsys import api
+
+        self.cfg = cfg
+        pcfg = program_config(cfg)
+        defs = jax.tree.map(lambda d: tuple(d.shape), api.param_defs(pcfg), is_leaf=is_def)
+        if defs != ref.weight_shapes(cfg):
+            raise ValueError(f"program parameters {defs} differ from the reference's")
+        self.step = make_serve_step(pcfg, recsys_rules())
+        self.peak = cfg["peak"]
+
+    def params(self, key):
+        """The program's parameters from `key`, built in one jitted call."""
+        from repro.core.quantization import quantize_tree
+
+        def build(k):
+            w = ref.make_weights(self.cfg, k)
+            return quantize_tree(w) if self.cfg["weights"] == "int8" else w
+
+        return jax.jit(build)(key)
+
+    def batch(self, tr, first: int, stop: int, rows: int) -> dict:
+        """Requests [first, stop) as `rows` pointwise rows, zero-padded."""
+        pool, cand = tr.row_index(first, stop)
+        out = {"user": tr.user[pool], "item": tr.cand_item[cand],
+               "category": tr.cand_category[cand],
+               "hist_item": tr.hist_item[pool], "hist_category": tr.hist_category[pool],
+               "hist_len": tr.hist_len[pool]}
+        return {k: np.pad(v, [(0, rows - len(pool))] + [(0, 0)] * (v.ndim - 1))
+                for k, v in out.items()}
+
+    def request_flops(self, n_cand: int) -> int:
+        return ref.request_flops(self.cfg, n_cand)
+
+    def reference(self, key, tr, pool: np.ndarray, cand: np.ndarray,
+                  control: bool = False) -> np.ndarray:
+        """Reference probabilities of the rows (pool user `pool`, candidate
+        `cand`). With `control`, the reference one precision step lower
+        than the configuration states: bfloat16 for float32, 4-bit for int8."""
+        cfg = self.cfg
+
+        def build(k):
+            w = ref.make_weights(cfg, k)
+            if cfg["weights"] == "int8":
+                return ref.quantize(w, 4 if control else 8)
+            return jax.tree.map(lambda x: x.astype(jnp.bfloat16), w) if control else w
+
+        with jax.default_matmul_precision("highest"):
+            w = jax.jit(build)(key)
+            enc = jax.jit(lambda w, hi, hc, hl: ref.pooled(w, hi, hc, hl, cfg))
+            score = jax.jit(lambda w, pooled, h, u, ci, cc:
+                            ref.scores(w, u, pooled[h], ci[:, None], cc[:, None], cfg)[:, 0])
+            users, h = np.unique(pool, return_inverse=True)  # each history encoded once
+            hb, rb = min(HIST_BLOCK, len(users)), min(ROW_BLOCK, len(pool))
+            pooled = jnp.concatenate([
+                enc(w, *(_block(a[users], i, hb)
+                         for a in (tr.hist_item, tr.hist_category, tr.hist_len)))
+                for i in range(0, len(users), hb)])
+            out = [np.asarray(score(w, pooled, *(_block(a, i, rb) for a in (
+                       h, tr.user[pool], tr.cand_item[cand], tr.cand_category[cand]))))
+                   for i in range(0, len(pool), rb)]
+        return np.concatenate(out)[:len(pool)].astype(np.float32)
+
+
+def _block(a: np.ndarray, i: int, n: int) -> np.ndarray:
+    """Rows [i, i + n) of `a`, zero-padded to n rows."""
+    b = a[i:i + n]
+    return np.pad(b, [(0, n - len(b))] + [(0, 0)] * (b.ndim - 1))
