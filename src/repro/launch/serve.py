@@ -59,8 +59,14 @@ def build_and_pretrain(cfg, rules, *, steps: int, batch: int):
 
 
 def make_serve_step(cfg, rules):
-    """The jitted serve step of one variant: probabilities for a batch."""
-    return jax.jit(lambda p, b: rec_api.serve(p, b, cfg, rules))
+    """The jitted serve step of one variant: probabilities for a batch. A
+    named function, so that its device module and every scope path in a
+    profiler trace read `jit(serve_step)/...`."""
+
+    def serve_step(p, b):
+        return rec_api.serve(p, b, cfg, rules)
+
+    return jax.jit(serve_step)
 
 
 def request_batches(cfg):

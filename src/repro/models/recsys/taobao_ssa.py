@@ -4,6 +4,18 @@ hist units (item⊕cat sum -> 64-d) + learned positions -> 2 pre-LN encoder
 blocks (4-head self-attention + FFN 64->256->64) -> masked mean pool ->
 tower([user16, cand64, pool64, pool*cand]) -> logit.
 
+The serve path runs under three sibling top-level `jax.named_scope`s, so
+that a profiler trace of the jitted step (`jit(serve_step)/<scope>/...` in
+each op's `op_name`) splits device time by layer. The names are stable:
+
+  embed    every field lookup, int8 gathers and their dequant included: the
+           user and candidate item/category rows, the history item/category
+           rows (one per candidate row in the pointwise batch) and the
+           positional add
+  encoder  the history mask, each block (`block{l}/attn`, `block{l}/ffn`)
+           and the masked mean (`pool`)
+  tower    the concat, the MLP and the output sigmoid
+
 Every projection is a compressible linear (core/lightweight.py), so the
 full §III ladder — grouped/low-rank (C1), pruning masks (C4), int8 (C5) —
 re-represents this model without touching this file. The teacher's
@@ -58,39 +70,46 @@ def _encoder_block(p, x, mask, n_heads: int, *, window: int = 0):
     attention mask (|i-j| < window) at the model level."""
     B, L, d = x.shape
     dh = d // n_heads
-    h = _ln(x, p["ln1"])
-    q = linear(p["wq"], h).reshape(B, L, n_heads, dh)
-    k = linear(p["wk"], h).reshape(B, L, n_heads, dh)
-    v = linear(p["wv"], h).reshape(B, L, n_heads, dh)
-    s = jnp.einsum("blhd,bmhd->bhlm", q, k) / jnp.sqrt(dh)
-    valid = mask[:, None, None, :]  # key mask
-    if window:
-        ij = jnp.abs(jnp.arange(L)[:, None] - jnp.arange(L)[None, :]) < window
-        valid = valid & ij[None, None]
-    s = jnp.where(valid, s, -1e30)
-    probs = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    o = jnp.einsum("bhlm,bmhd->blhd", probs.astype(v.dtype), v).reshape(B, L, d)
-    x = x + linear(p["wo"], o)
-    h2 = _ln(x, p["ln2"])
-    x = x + linear(p["w2"], jax.nn.relu(linear(p["w1"], h2)))
+    with jax.named_scope("attn"):
+        h = _ln(x, p["ln1"])
+        q = linear(p["wq"], h).reshape(B, L, n_heads, dh)
+        k = linear(p["wk"], h).reshape(B, L, n_heads, dh)
+        v = linear(p["wv"], h).reshape(B, L, n_heads, dh)
+        s = jnp.einsum("blhd,bmhd->bhlm", q, k) / jnp.sqrt(dh)
+        valid = mask[:, None, None, :]  # key mask
+        if window:
+            ij = jnp.abs(jnp.arange(L)[:, None] - jnp.arange(L)[None, :]) < window
+            valid = valid & ij[None, None]
+        s = jnp.where(valid, s, -1e30)
+        probs = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+        o = jnp.einsum("bhlm,bmhd->blhd", probs.astype(v.dtype), v).reshape(B, L, d)
+        x = x + linear(p["wo"], o)
+    with jax.named_scope("ffn"):
+        h2 = _ln(x, p["ln2"])
+        x = x + linear(p["w2"], jax.nn.relu(linear(p["w1"], h2)))
     return x, probs
 
 
 def encode_history(params, batch, cfg: RecSysConfig, rules, collect_attn=False):
     """-> (pooled [B,d], attn list per layer)."""
     t = params["tables"]
-    it = field_lookup(t, cfg, "hist_item", batch["hist_item"], rules)
-    ca = field_lookup(t, cfg, "hist_category", batch["hist_category"], rules)
-    x = it + ca + params["pos"][None]
-    mask = jnp.arange(x.shape[1])[None] < batch["hist_len"][:, None]
-    window = cfg_window(cfg)
-    attns = []
-    for l in range(cfg.n_attn_layers):
-        x, probs = _encoder_block(params[f"enc{l}"], x, mask, cfg.n_heads, window=window)
-        if collect_attn:
-            attns.append(probs)
-    m = mask[..., None].astype(x.dtype)
-    pooled = jnp.sum(x * m, axis=1) / jnp.clip(jnp.sum(m, axis=1), 1.0)
+    with jax.named_scope("embed"):
+        it = field_lookup(t, cfg, "hist_item", batch["hist_item"], rules)
+        ca = field_lookup(t, cfg, "hist_category", batch["hist_category"], rules)
+        x = it + ca + params["pos"][None]
+    with jax.named_scope("encoder"):
+        mask = jnp.arange(x.shape[1])[None] < batch["hist_len"][:, None]
+        window = cfg_window(cfg)
+        attns = []
+        for l in range(cfg.n_attn_layers):
+            with jax.named_scope(f"block{l}"):
+                x, probs = _encoder_block(params[f"enc{l}"], x, mask, cfg.n_heads,
+                                          window=window)
+            if collect_attn:
+                attns.append(probs)
+        with jax.named_scope("pool"):
+            m = mask[..., None].astype(x.dtype)
+            pooled = jnp.sum(x * m, axis=1) / jnp.clip(jnp.sum(m, axis=1), 1.0)
     return pooled, attns
 
 
@@ -107,13 +126,15 @@ def _tower_logits(params, user, cand, pooled, cfg):
 
 def logits_and_attn(params, batch, cfg: RecSysConfig, rules, collect_attn=False):
     t = params["tables"]
-    user = field_lookup(t, cfg, "user", batch["user"], rules)
-    it = field_lookup(t, cfg, "item", batch["item"], rules)
-    ca = field_lookup(t, cfg, "category", batch["category"], rules)
-    cand = it + ca
+    with jax.named_scope("embed"):
+        user = field_lookup(t, cfg, "user", batch["user"], rules)
+        it = field_lookup(t, cfg, "item", batch["item"], rules)
+        ca = field_lookup(t, cfg, "category", batch["category"], rules)
+        cand = it + ca
     pooled, attns = encode_history(params, batch, cfg, rules, collect_attn)
-    out = _tower_logits(params, user, cand, pooled, cfg)
-    return constrain(out, ("batch",), rules), attns
+    with jax.named_scope("tower"):
+        out = constrain(_tower_logits(params, user, cand, pooled, cfg), ("batch",), rules)
+    return out, attns
 
 
 def logits(params, batch, cfg, rules):
@@ -127,7 +148,9 @@ def loss(params, batch, cfg: RecSysConfig, rules):
 
 
 def serve(params, batch, cfg: RecSysConfig, rules):
-    return jax.nn.sigmoid(logits(params, batch, cfg, rules))
+    lg = logits(params, batch, cfg, rules)
+    with jax.named_scope("tower"):
+        return jax.nn.sigmoid(lg)
 
 
 def retrieval(params, query, cand_ids, cfg: RecSysConfig, rules):
