@@ -12,8 +12,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 
 import jax
+import numpy as np
 
 from repro.core.compression_loop import LadderConfig, run_ladder, variant_stats
 from repro.core.serving.engine import ElasticEngine, EngineConfig, poisson_arrivals
@@ -58,15 +60,120 @@ def build_and_pretrain(cfg, rules, *, steps: int, batch: int):
     return params, data, losses
 
 
-def make_serve_step(cfg, rules):
-    """The jitted serve step of one variant: probabilities for a batch. A
-    named function, so that its device module and every scope path in a
-    profiler trace read `jit(serve_step)/...`."""
+ROWS_PER_HISTORY = 8  # a compact batch holds one history per 8 rows
 
-    def serve_step(p, b):
-        return rec_api.serve(p, b, cfg, rules)
 
-    return jax.jit(serve_step)
+def history_runs(batch: dict, keys) -> tuple:
+    """(first row of each run, run of each row) for the runs of adjacent
+    rows of `batch` whose arrays under `keys` are all equal."""
+    rows = len(batch[keys[0]])
+    same = np.ones(rows - 1, bool)
+    for k in keys:
+        a = batch[k].reshape(rows, -1)
+        same &= (a[1:] == a[:-1]).all(axis=1)
+    new = np.ones(rows, bool)
+    new[1:] = ~same
+    return np.flatnonzero(new), (np.cumsum(new) - 1).astype(np.int32)
+
+
+def _first_rows(a: np.ndarray, n: int) -> np.ndarray:
+    """The first `n` rows of `a`, zero-padded to `n`."""
+    out = np.zeros((n,) + a.shape[1:], a.dtype)
+    out[:min(n, len(a))] = a[:n]
+    return out
+
+
+def _unpack(b: dict, layout: tuple) -> dict:
+    """`b` with its "hist_block" split back into the history arrays that
+    `layout` names, each with its trailing shape."""
+    out = {k: v for k, v in b.items() if k != "hist_block"}
+    col = 0
+    for k, shape in layout:
+        width = math.prod(shape)
+        out[k] = b["hist_block"][:, col:col + width].reshape((-1,) + shape)
+        col += width
+    return out
+
+
+class ServeStep:
+    """The serve step of one variant: probabilities for a pointwise batch.
+
+    `jitted` is the jitted function `serve_step`, so that its device module
+    and every scope path in a profiler trace read `jit(serve_step)/...`.
+
+    The candidate rows of one request are adjacent and repeat its history.
+    Where the family declares its candidate-independent history keys
+    (`api.history_keys`) and the batch holds them as host numpy arrays of
+    one dtype, a call encodes each run of equal adjacent histories once: it
+    sends the run's first row, at capacity `rows // ROWS_PER_HISTORY` where
+    the runs fit and `rows` where not, with `hist_row`, the run of each row.
+    The histories cross to the device as one array, "hist_block", since
+    every array a call sends costs the host a copy of its own; `layout`
+    says how the step splits it. Every other batch (device arrays, tracers,
+    shapes) goes to `jitted` as it is. The first call at a row count runs
+    both of its capacities, so what a later batch holds never compiles.
+    Counters, over compacted calls: `rows` served, `histories` (runs found)
+    and `encoded` (capacity sent).
+    """
+
+    def __init__(self, cfg, rules):
+        def serve_step(p, b, layout=None):
+            if layout:
+                with jax.named_scope("embed"):
+                    b = _unpack(b, layout)
+            return rec_api.serve(p, b, cfg, rules)
+
+        self.jitted = jax.jit(serve_step, static_argnames="layout")
+        self.keys = rec_api.history_keys(cfg)
+        self.rows = self.histories = self.encoded = 0
+        self._warm = set()
+
+    def compact(self, b: dict):
+        """(`b` with each run's history once in "hist_block" and `hist_row`,
+        the block's layout), or None where `b` goes to the step as it is."""
+        if not self.keys or not all(isinstance(b.get(k), np.ndarray) for k in self.keys) \
+                or len({b[k].dtype for k in self.keys}) > 1:
+            return None
+        with jax.profiler.TraceAnnotation("serve_step.compact"):
+            first, hist_row = history_runs(b, self.keys)
+            rows = len(hist_row)
+            cap = rows // ROWS_PER_HISTORY
+            if len(first) > cap:
+                cap = rows
+            block = np.concatenate([b[k][first].reshape(len(first), -1) for k in self.keys],
+                                   axis=1)
+            out = {k: v for k, v in b.items() if k not in self.keys}
+            out.update(hist_row=hist_row, hist_block=_first_rows(block, cap))
+            return out, tuple((k, b[k].shape[1:]) for k in self.keys)
+
+    def __call__(self, p, b):
+        c = self.compact(b)
+        if c is None:
+            return self.jitted(p, b)
+        batch, layout = c
+        hist_row = batch["hist_row"]
+        rows, cap = len(hist_row), len(batch["hist_block"])
+        if rows not in self._warm:
+            self._warm.add(rows)
+            other = rows // ROWS_PER_HISTORY if cap == rows else rows
+            if other:  # the capacity a later batch of this many rows may need
+                self.jitted(p, {**batch, "hist_row": np.minimum(hist_row, other - 1),
+                                "hist_block": _first_rows(batch["hist_block"], other)},
+                            layout=layout)
+        self.rows += rows
+        self.histories += int(hist_row[-1]) + 1
+        self.encoded += cap
+        return self.jitted(p, batch, layout=layout)
+
+    def lower(self, p, b):
+        """`jitted` lowered for what a call with `b` sends."""
+        c = self.compact(b)
+        return self.jitted.lower(p, b) if c is None else self.jitted.lower(p, c[0], layout=c[1])
+
+
+def make_serve_step(cfg, rules) -> ServeStep:
+    """The serve step of one variant (`ServeStep`)."""
+    return ServeStep(cfg, rules)
 
 
 def request_batches(cfg):

@@ -17,6 +17,13 @@ def module_for(cfg: RecSysConfig):
     return _MODULES[cfg.interaction]
 
 
+def history_keys(cfg) -> tuple:
+    """The batch keys that make up the family's candidate-independent
+    history, which its `serve` accepts once per distinct history beside
+    `hist_row`; () where the history is pooled per candidate or absent."""
+    return getattr(module_for(cfg), "HISTORY_KEYS", ())
+
+
 def param_defs(cfg):
     return module_for(cfg).param_defs(cfg)
 

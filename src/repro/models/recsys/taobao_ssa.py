@@ -10,11 +10,17 @@ each op's `op_name`) splits device time by layer. The names are stable:
 
   embed    every field lookup, int8 gathers and their dequant included: the
            user and candidate item/category rows, the history item/category
-           rows (one per candidate row in the pointwise batch) and the
-           positional add
+           rows (one per history the batch holds) and the positional add
   encoder  the history mask, each block (`block{l}/attn`, `block{l}/ffn`)
-           and the masked mean (`pool`)
+           and the masked mean with its take to the rows (`pool`)
   tower    the concat, the MLP and the output sigmoid
+
+The history encoding depends on `HISTORY_KEYS` alone, not on the candidate.
+So a serve batch may hold each distinct history once: with `hist_row`
+(int32[rows]) present, `hist_item`/`hist_category`/`hist_len` hold `cap`
+histories, the encoder runs on those, and each candidate row takes the
+pooled vector of history `hist_row[i]` (a gather under `encoder/pool`).
+Without `hist_row` every row carries its own history.
 
 Every projection is a compressible linear (core/lightweight.py), so the
 full §III ladder — grouped/low-rank (C1), pruning masks (C4), int8 (C5) —
@@ -34,6 +40,9 @@ from repro.distributed.sharding import constrain
 from repro.models.common import ParamDef
 from repro.models.recsys.embedding import _take_rows, field_lookup, named_table_defs
 from repro.models.recsys.rec_layers import bce_with_logits, mlp_apply, mlp_defs
+
+# the batch keys the history encoding reads; it reads no candidate key
+HISTORY_KEYS = ("hist_item", "hist_category", "hist_len")
 
 
 def param_defs(cfg: RecSysConfig) -> Dict:
@@ -91,7 +100,9 @@ def _encoder_block(p, x, mask, n_heads: int, *, window: int = 0):
 
 
 def encode_history(params, batch, cfg: RecSysConfig, rules, collect_attn=False):
-    """-> (pooled [B,d], attn list per layer)."""
+    """-> (pooled [B,d], attn list per layer). With `hist_row` in `batch`,
+    pooled has one row per entry of `hist_row`, and the attention maps one
+    per history."""
     t = params["tables"]
     with jax.named_scope("embed"):
         it = field_lookup(t, cfg, "hist_item", batch["hist_item"], rules)
@@ -110,6 +121,8 @@ def encode_history(params, batch, cfg: RecSysConfig, rules, collect_attn=False):
         with jax.named_scope("pool"):
             m = mask[..., None].astype(x.dtype)
             pooled = jnp.sum(x * m, axis=1) / jnp.clip(jnp.sum(m, axis=1), 1.0)
+            if "hist_row" in batch:
+                pooled = pooled[batch["hist_row"]]
     return pooled, attns
 
 

@@ -11,6 +11,7 @@ import functools
 import importlib.util
 import json
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -90,6 +91,32 @@ def test_serve_step_compiles_at_published_widths(published, one_chip, variant):
     batch = {k: v for k, v in published["batch"].items() if k != "label"}
     step = make_serve_step(published["cfg"], published["rules"])
     _fits(step.lower(params, batch).compile())
+
+
+@pytest.mark.parametrize("variant,rows", [("baseline", 4096), ("quantized", 256)])
+def test_compact_serve_step_compiles_at_published_widths(published, one_chip, variant,
+                                                         rows):
+    """Each distinct history once: the encoder and the history gathers are
+    sized by the capacity, never by the rows."""
+    from repro.core.quantization import quantize_tree
+    from repro.launch.serve import ROWS_PER_HISTORY, make_serve_step
+
+    params = published["params"]
+    if variant == "quantized":
+        params = _on(jax.eval_shape(quantize_tree, params), one_chip)
+    cap = rows // ROWS_PER_HISTORY
+    per_row = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    batch = {"user": per_row, "item": per_row, "category": per_row, "hist_row": per_row,
+             "hist_block": jax.ShapeDtypeStruct((cap, 2 * L + 1), jnp.int32,
+                                                sharding=one_chip)}
+    layout = (("hist_item", (L,)), ("hist_category", (L,)), ("hist_len", ()))
+    step = make_serve_step(published["cfg"], published["rules"])
+    compiled = step.jitted.lower(params, batch, layout=layout).compile()
+    _fits(compiled)
+    shapes = [tuple(map(int, s.split(",")))
+              for s in re.findall(r"= \w+\[([\d,]+)\]", compiled.as_text())]
+    assert [s for s in shapes if s[0] == cap and L in s[1:]]  # the histories, once
+    assert not [s for s in shapes if (s[0] == rows and L in s[1:]) or s[0] == rows * L]
 
 
 def test_train_step_compiles_at_published_widths(published, one_chip):
