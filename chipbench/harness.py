@@ -223,8 +223,15 @@ def load_cell(spec: dict, name: str) -> tuple:
     return cell, cfg, mix
 
 
+def driver(cfg: dict):
+    """The driver module `chipbench/models/<cfg["model"]>.py`, to the
+    contract in `chipbench/models/__init__.py`."""
+    return importlib.import_module(f"chipbench.models.{cfg['model']}")
+
+
 def model_for(cfg: dict):
-    return importlib.import_module(f"chipbench.models.{cfg['model']}").Model(cfg)
+    """`Model(cfg)` of the configuration's driver."""
+    return driver(cfg).Model(cfg)
 
 
 def metric_reader(name: str) -> Callable:

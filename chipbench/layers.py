@@ -7,8 +7,10 @@ with its first `harness.TRACE_SECONDS` traced. It prints one JSON line:
 
 - `layer_us_per_row`: device time of each program layer per real row
   dispatched in the traced window. An op's layer is the first scope of its
-  `op_name` path after the `jit(...)` prefix: `embed`, `encoder` or `tower`
-  (the scopes of `repro.models.recsys.taobao_ssa`), and `unscoped` otherwise.
+  `op_name` path after the `jit(...)` prefix: `embed`, `encoder` or `tower`,
+  and `unscoped` otherwise. `repro.models.recsys.taobao_ssa` names its
+  layers with these scopes; any other model whose serve step names its
+  layers with them is split the same way, with no edit here.
   The TPU's op events carry no `op_name`, so the path comes from the
   optimized HLO of each bucket's executable, by the op's name, result shape
   and opcode. A layer's time is the union of its ops' intervals, so layers

@@ -21,6 +21,9 @@ from chipbench.reference import taobao_ssa as ref
 HIST_BLOCK = 512     # histories per reference call
 ROW_BLOCK = 65536    # candidate rows per reference call
 BATCH_KEYS = ("user", "item", "category", "hist_item", "hist_category", "hist_len")
+CPU_CUT = dict(users=2000, items=3000, categories=300)
+CPU_WIDTHS = dict(seq_len=20, d_model=16, embed_dim=16, d_ff=64, n_heads=2, tower=[24, 8],
+                  user_dim=8)
 
 
 def program_config(cfg: dict):
@@ -104,8 +107,8 @@ class Model:
                 enc(w, *(_block(a[users], i, hb)
                          for a in (tr.hist_item, tr.hist_category, tr.hist_len)))
                 for i in range(0, len(users), hb)])
-            out = [np.asarray(score(w, pooled, *(_block(a, i, rb) for a in (
-                       h, tr.user[pool], tr.cand_item[cand], tr.cand_category[cand]))))
+            rows = (h, tr.user[pool], tr.cand_item[cand], tr.cand_category[cand])
+            out = [np.asarray(score(w, pooled, *(_block(a, i, rb) for a in rows)))
                    for i in range(0, len(pool), rb)]
         return np.concatenate(out)[:len(pool)].astype(np.float32)
 

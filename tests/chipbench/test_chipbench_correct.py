@@ -2,16 +2,13 @@
 
 The control is the reference one precision step below the configuration
 (bfloat16 for float32, 4-bit for int8), at the published widths with the
-vocabularies cut."""
+vocabularies cut by each configuration's driver."""
 from __future__ import annotations
 
-import jax
-import numpy as np
 import pytest
 
-from chipbench_testing import config, mix, run_small, spec
-from chipbench import harness, traffic
-from chipbench.models import taobao_ssa
+from chipbench_testing import config, control_readings, run_small, spec
+from chipbench import harness
 
 CELLS = [w["name"] for w in spec()["workloads"]]
 
@@ -35,11 +32,6 @@ def test_a_traced_run_is_correct_and_reads_its_window():
 @pytest.mark.parametrize("name", sorted({c["name"] for c in spec()["configs"]}))
 def test_the_control_fails_a_limit(name):
     cfg = config(name)
-    tr = traffic.make_traffic(mix("rank50-saturated"), cfg, 17, 1.0)
-    model = taobao_ssa.Model(cfg)
-    key = jax.random.key(17)
-    rows = tr.row_index(0, tr.contents)
-    control = harness.readings(model.reference(key, tr, *rows, control=True).astype(np.float64),
-                               model.reference(key, tr, *rows).astype(np.float64))
+    control = control_readings(harness.model_for(cfg), cfg)
     failed = [n for n, lim in cfg["limits"].items() if control[n] > lim]
     assert failed, (control, cfg["limits"])

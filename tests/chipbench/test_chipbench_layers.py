@@ -151,11 +151,7 @@ def test_a_whole_split_at_small_widths(cell_name):
 
     from chipbench import harness
 
-    spec = chipbench_testing.spec()
-    cell, cfg, m = harness.load_cell(spec, cell_name)
-    cfg = chipbench_testing.config(cfg["name"], **chipbench_testing.SMALL)
-    m = chipbench_testing.mix(cell["traffic"], hist_len=[5, 20],
-                              **({"rate_per_s": 300.0} if m["arrivals"] == "poisson" else {}))
+    cell, cfg, m = chipbench_testing.small_cell(cell_name)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(harness, "require_chips", lambda n: jax.devices()[:n])
         traced = layers.split(cell, cfg, m, 2**31 + 7, 0.5, True)
